@@ -9,6 +9,8 @@ from repro.core.device_spec import (
     SPECS,
     TPU_POD_256,
     TPU_SUPERPOD_512,
+    V5E_1,
+    V5E_2X2,
     DeviceSpec,
     InstanceNode,
     multi_gpu,
@@ -107,6 +109,7 @@ from repro.core.timing import ReplayEngine, TimingEngine, make_engine
 
 __all__ = [
     "A30", "A100", "H100", "SPECS", "TPU_POD_256", "TPU_SUPERPOD_512",
+    "V5E_1", "V5E_2X2",
     "DeviceSpec", "InstanceNode", "multi_gpu",
     "Task", "Profile", "bind_tasks", "remainder_task", "transfer_profile",
     "Schedule", "ScheduledTask",

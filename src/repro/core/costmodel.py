@@ -60,7 +60,10 @@ def step_time(cfg: ArchConfig, shape: ShapeConfig, slices: int,
               chips_per_slice: int = 32) -> float:
     """Seconds per step on a size-``slices`` instance."""
     chips = slices * chips_per_slice
-    dp = chips // MODEL_AXIS
+    # below one full model axis (a 1-4 chip host) the model axis shrinks
+    # to the chips there are, and data parallelism is at least 1
+    model_axis = min(MODEL_AXIS, chips)
+    dp = max(chips // model_axis, 1)
     n_params = cfg.param_count()
     n_active = cfg.active_param_count()
     tokens = shape.global_batch * (
@@ -96,7 +99,7 @@ def step_time(cfg: ArchConfig, shape: ShapeConfig, slices: int,
     param_bytes = n_params * 2
     opt_bytes = n_params * 8 if train else 0
     act_bytes_per_chip = (
-        tokens / dp * cfg.d_model * 2 * cfg.n_layers * 4 / MODEL_AXIS
+        tokens / dp * cfg.d_model * 2 * cfg.n_layers * 4 / model_axis
     )
     if shape.kind == "decode":
         # KV-cache / state read dominates
@@ -125,7 +128,7 @@ def step_time(cfg: ArchConfig, shape: ShapeConfig, slices: int,
     act_ar = 2 * (tokens / dp) * cfg.d_model * 2 * cfg.n_layers * 2
     if shape.kind == "decode":
         act_ar = 2 * (tokens / dp) * cfg.d_model * 2 * cfg.n_layers * 2
-    grad_ar = 2 * param_bytes / max(dp, 1) if train else 0.0
+    grad_ar = 2 * param_bytes / dp if train else 0.0
     t_coll = (act_ar + grad_ar) / ICI_BW
 
     return max(t_compute, t_memory, t_coll) * spill

@@ -13,7 +13,8 @@ sizes ``C_G`` and the reconfiguration-cost tables (paper Table 1).
 
 Paper-faithful specs: ``A30``, ``A100``, ``H100``.
 TPU-adapted specs (DESIGN.md §2): ``TPU_POD_256`` (8 pod-slices of 32 chips,
-full binary tree) and ``TPU_SUPERPOD_512`` (two such pods as a forest).
+full binary tree) and ``TPU_SUPERPOD_512`` (two such pods as a forest);
+``V5E_1`` and ``V5E_2X2`` (one chip per slice) for live runs on one v5e host.
 """
 
 from __future__ import annotations
@@ -354,10 +355,36 @@ TPU_SUPERPOD_512 = dataclasses.replace(
     multi_gpu(TPU_POD_256, 2), name="TPU_SUPERPOD_512"
 )
 
+# ---------------------------------------------------------------------------
+# One TPU v5e host, where live execution runs: a slice is one chip and a
+# size-s instance is a sub-mesh of s chips.  ``V5E_1`` is a single chip (one
+# instance, nothing to repartition); ``V5E_2X2`` is the four-chip host as a
+# binary tree 4 -> 2+2 -> 1+1+1+1.  Formation costs reuse the pod budget
+# above; they are assumed, not measured on the host.
+# ---------------------------------------------------------------------------
+
+V5E_1 = DeviceSpec(
+    name="V5E_1",
+    roots=(_binary_tree(0, 0, 1),),
+    sizes=(1,),
+    t_create={1: 1.0},
+    t_destroy={1: 0.5},
+)
+
+V5E_2X2 = DeviceSpec(
+    name="V5E_2X2",
+    roots=(_binary_tree(0, 0, 4),),
+    sizes=(1, 2, 4),
+    t_create={1: 1.0, 2: 1.2, 4: 1.6},
+    t_destroy={1: 0.5, 2: 0.6, 4: 0.8},
+)
+
 SPECS: dict[str, DeviceSpec] = {
     "A30": A30,
     "A100": A100,
     "H100": H100,
     "TPU_POD_256": TPU_POD_256,
     "TPU_SUPERPOD_512": TPU_SUPERPOD_512,
+    "V5E_1": V5E_1,
+    "V5E_2X2": V5E_2X2,
 }

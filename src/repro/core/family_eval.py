@@ -37,19 +37,21 @@ exactly one place (:func:`_winner_scan`):
   consecutive candidates differ in exactly one task
   (``allocation_family_deltas``), so a chunk is a boolean membership
   tensor over those fixed orders, built by two column flips per candidate.
-  The simulation itself is a jax-jitted ``lax.scan`` in float64 (the
-  repo's accelerator toolchain; compiled once per shape bucket and cached)
-  and the resulting per-node duration chains are scored with the batched
-  :func:`~repro.core.timing.chains_makespan_batch`.  Without jax the
-  evaluator transparently falls back to sequential scoring — same
-  results, no speedup.
+  The simulation is a jax-jitted ``lax.scan`` in float64 and the
+  resulting per-node duration chains are scored by a jitted event walk
+  (the batched form of :func:`~repro.core.timing.chains_makespan`); both
+  run on jax's default device, compiled once per shape bucket and
+  cached.  It needs jax, and raises without it.  Off the CPU backend
+  each chunk is checked against the host, and a device whose float64
+  is not IEEE raises :class:`DeviceMismatchError` at the first
+  candidate it scores differently.
 * ``"auto"`` — three-way dispatch: ``"incremental"`` when the C backend
   is buildable and the batch clears ``AUTO_MIN_TASKS_INCREMENTAL``,
-  else ``"vectorized"`` when jax is importable and the batch/family are
-  large enough to amortize the array program (``AUTO_MIN_TASKS`` pruned
-  / ``AUTO_MIN_TASKS_UNPRUNED`` full-family, with ``AUTO_MIN_FAMILY``),
-  else ``"sequential"``.  ``SchedulerConfig(evaluator_floor=)``
-  overrides the task floors.
+  else ``"vectorized"`` when jax is importable, computes on the CPU and
+  the batch/family are large enough to amortize the array program
+  (``AUTO_MIN_TASKS`` pruned / ``AUTO_MIN_TASKS_UNPRUNED`` full-family,
+  with ``AUTO_MIN_FAMILY``), else ``"sequential"``.
+  ``SchedulerConfig(evaluator_floor=)`` overrides the task floors.
 
 **Equivalence contract:** every evaluator returns a bit-identical winner —
 index, allocation, assignment and makespan — for any workload and spec.
@@ -84,8 +86,9 @@ from repro.core.repartition import (
 )
 from repro.core.timing import (
     IdentityCache,
+    _batch_spec_arrays,
     chains_makespan,
-    chains_makespan_batch,
+    left_fold,
 )
 
 # jax is probed, not imported: `import repro.core` must stay free of
@@ -95,16 +98,20 @@ import importlib.util
 
 HAVE_JAX = importlib.util.find_spec("jax") is not None
 
-_WARNED_NO_JAX = False
-
 
 def _jax_modules():
-    """(jax, jax.numpy, enable_x64), imported on first use."""
+    """(jax, jax.numpy), imported on first use."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    return jax, jnp, enable_x64
+    return jax, jnp
+
+
+def _platform() -> str:
+    """jax's default backend.  Only ``"cpu"`` is known to compute float64
+    in IEEE arithmetic (the TPU emulates it with pairs of float32)."""
+    jax, _ = _jax_modules()
+    return jax.default_backend()
 
 #: "auto" dispatch thresholds, calibrated on the container benchmarks
 #: (benchmarks/t_cost.py, paired medians).  The incremental evaluator's
@@ -196,7 +203,7 @@ def resolve_evaluator(config, n_tasks: int, family_size: int) -> str:
 
             if fastsim.available():
                 return "incremental"
-        if HAVE_JAX and n_tasks >= floor_vec:
+        if HAVE_JAX and n_tasks >= floor_vec and _platform() == "cpu":
             return "vectorized"
         return "sequential"
     if name in ("vectorized", "incremental", "parallel") \
@@ -213,13 +220,13 @@ def family_areas(
 ) -> np.ndarray:
     """Prune area of every family candidate, by one-task delta recurrence.
 
-    ``area_0`` is the plain left-fold sum over the first allocation;
+    ``area_0`` is the :func:`left_fold` sum over the first allocation;
     ``area_{i+1} = area_i + (s_new * t(s_new) - s_old * t(s_old))`` via
     ``np.add.accumulate`` — the same IEEE additions whether the recurrence
     runs here or one step at a time, so both evaluators see identical
     values.  O(n + family) total instead of O(n) per candidate.
     """
-    area0 = sum(s * t.times[s] for t, s in zip(tasks, first))
+    area0 = left_fold(0, (s * t.times[s] for t, s in zip(tasks, first)))
     if not deltas:
         return np.array([area0])
     alloc = list(first)
@@ -742,8 +749,9 @@ class IncrementalEvaluator(FamilyEvaluator):
 PARALLEL_PRUNED_CHUNK = 32
 
 
-def _parallel_chunk_scores(payload):
-    """Pool worker: full Algorithm-1 scores of family chunk ``[lo, hi)``.
+def _chunk_scores(payload):
+    """Full Algorithm-1 scores of family chunk ``[lo, hi)`` (the parallel
+    evaluator's pool worker, and the vectorized evaluator's host check).
 
     Warm-starts :class:`LPTGroups` at candidate ``lo`` (the maintained
     order is bit-identical to a cold sort) and scores every candidate of
@@ -836,7 +844,7 @@ class ParallelEvaluator(FamilyEvaluator):
                 ):
                     lo, hi = bounds[submitted["next"]]
                     futures[submitted["next"]] = pool.submit(
-                        _parallel_chunk_scores,
+                        _chunk_scores,
                         (tasks, spec, first, deltas, lo, hi),
                     )
                     submitted["next"] += 1
@@ -873,10 +881,17 @@ class ParallelEvaluator(FamilyEvaluator):
 # -- vectorized array program -----------------------------------------------
 
 _SPEC_CACHE = IdentityCache(16)       # spec -> _SpecArrays
-_PROGRAM_CACHE = IdentityCache(64)    # (spec, (C, L)) -> jitted program
+_PROGRAM_CACHE = IdentityCache(64)    # (spec, kind, C, L) -> jitted program
 
 _BIG_SEQ = np.int32(2**30)
 
+
+class DeviceMismatchError(RuntimeError):
+    """The device's float64 results differ from the host's IEEE ones.
+
+    Raised by ``evaluator="vectorized"`` off the CPU backend, where float64
+    may be emulated (the TPU computes it with pairs of float32), instead of
+    returning a winner that could differ from ``"sequential"``."""
 
 
 @dataclasses.dataclass
@@ -888,7 +903,12 @@ class _SpecArrays:
     n_sizes: int
     node_sizeidx: np.ndarray   # (N,) size-axis index per node
     node_keys: list            # (N,) NodeKey per node
-    proj: np.ndarray           # (N, S+4+2N) selection-projection matrix
+    size_onehot: np.ndarray    # (N, S) bool: node n has size s
+    tc: np.ndarray             # (N,) creation charge per node
+    td: np.ndarray             # (N,) destruction charge per node
+    nch: np.ndarray            # (N,) child count per node
+    childmask: np.ndarray      # (N, N) bool: [p, c] c is a child of p
+    childrank: np.ndarray      # (N, N) int32: push rank of child c of p
     theap0: np.ndarray         # (N,) initial heap times (roots 0, else inf)
     tseq0: np.ndarray          # (N,) initial heap seqs (roots 0..R-1)
     seq0: int                  # first free seq (= number of roots)
@@ -904,25 +924,14 @@ def _spec_eval_arrays(spec: DeviceSpec) -> _SpecArrays:
     sizeidx = {s: k for k, s in enumerate(spec.sizes)}
     index = {node.key: i for i, node in enumerate(nodes)}
     node_sizeidx = np.array([sizeidx[node.size] for node in nodes])
-    size_onehot = np.zeros((N, S))
-    size_onehot[np.arange(N), node_sizeidx] = 1.0
-    tc = np.array([spec.t_create[node.size] for node in nodes])
-    td = np.array([spec.t_destroy[node.size] for node in nodes])
-    nid = np.arange(N, dtype=np.float64)
-    nch = np.array([len(node.children) for node in nodes], dtype=np.float64)
-    childmask = np.zeros((N, N))
-    childrank = np.zeros((N, N))
+    size_onehot = np.zeros((N, S), dtype=bool)
+    size_onehot[np.arange(N), node_sizeidx] = True
+    childmask = np.zeros((N, N), dtype=bool)
+    childrank = np.zeros((N, N), dtype=np.int32)
     for i, node in enumerate(nodes):
         for rank, child in enumerate(node.children):
-            childmask[i, index[child.key]] = 1.0
-            childrank[i, index[child.key]] = float(rank)
-    # one (C,N) @ (N, S+4+2N) matmul projects everything the step needs
-    # out of the selected node's row: its size, reconfiguration costs, id,
-    # child count, children mask and child push ranks.
-    proj = np.concatenate(
-        [size_onehot, tc[:, None], td[:, None], nid[:, None], nch[:, None],
-         childmask, childrank], axis=1,
-    )
+            childmask[i, index[child.key]] = True
+            childrank[i, index[child.key]] = rank
     theap0 = np.full(N, np.inf)
     tseq0 = np.full(N, _BIG_SEQ, dtype=np.int32)
     roots = [index[r.key] for r in spec.roots]
@@ -930,8 +939,11 @@ def _spec_eval_arrays(spec: DeviceSpec) -> _SpecArrays:
         theap0[i] = 0.0
         tseq0[i] = rank
     out = _SpecArrays(
-        spec, N, S, node_sizeidx, [node.key for node in nodes],
-        proj, theap0, tseq0, len(roots),
+        spec, N, S, node_sizeidx, [node.key for node in nodes], size_onehot,
+        np.array([spec.t_create[node.size] for node in nodes]),
+        np.array([spec.t_destroy[node.size] for node in nodes]),
+        np.array([len(node.children) for node in nodes], dtype=np.int32),
+        childmask, childrank, theap0, tseq0, len(roots),
     )
     _SPEC_CACHE.put(spec, out)
     return out
@@ -948,39 +960,39 @@ def _phase_a_program(sa: _SpecArrays, C: int, L: int) -> Callable:
     pops in exactly the same order as the sequential runs-with-shortcut
     code (see ``_list_schedule_arrays``), and every reconfiguration /
     chain addition is a single f64 op in the same order, so the recorded
-    pops are bit-identical to the sequential simulation.  Total steps are
-    bounded by ``n + N``: every task is placed exactly once and each node
-    leaves the heap at most once.
+    pops are bit-identical to the sequential simulation.  The popped
+    node's constants are read with masked selects over the one-hot pop
+    mask — selections, never products — so no rounding enters there.
+    Total steps are bounded by ``n + N``: every task is placed exactly
+    once and each node leaves the heap at most once.
 
-    Returns ``run(gdurs, glen) -> (nid, dur, pos)``, three ``(T, C)``
-    step records: the popped node id when candidate ``c``'s ``t``-th pop
-    placed a task (else -1), the placed duration, and the task's position
-    in that node's chain.  The program is a ``lax.scan`` (stacked step
-    outputs write into a preallocated buffer; a recording while_loop
-    carry would copy the whole record every iteration, which on the CPU
-    backend costs ~60x the step's arithmetic).  The op mix is deliberate:
-    native min-reduces, one small matmul and one tiny gather per step —
-    measured faster on the CPU backend than every "clever" alternative
-    tried (variadic lax.reduce lex-min comparators, stacked payload
-    tensors, block-amortized sliding-window duration lookups).
+    Returns ``run(gdurs, glen) -> (nid, chain_durs, chain_len)``: the
+    ``(T, C)`` record of the node each step placed a task on (-1 when it
+    placed none), and every candidate's per-node duration chains as a
+    zero-padded ``(C, N, L)`` tensor with its ``(C, N)`` lengths — the
+    input of :func:`_chains_program`.  The program is a ``lax.scan``
+    (stacked step outputs write into a preallocated buffer; a recording
+    while_loop carry would copy the whole record every iteration).
+    Call, trace and run it inside ``jax.enable_x64(True)``.
     """
-    cached = _PROGRAM_CACHE.get(sa.spec, (C, L))
+    cached = _PROGRAM_CACHE.get(sa.spec, ("phase_a", C, L))
     if cached is not None:
         return cached
-    jax, jnp, _ = _jax_modules()
+    jax, jnp = _jax_modules()
     N = sa.n_nodes
     S = sa.n_sizes
     T = L + N
     INF = np.inf
-    proj = jnp.asarray(sa.proj)
-    theap0 = jnp.asarray(sa.theap0)
-    tseq0 = jnp.asarray(sa.tseq0)
-    seq0 = np.int32(sa.seq0)
-    sizebase = jnp.asarray(np.arange(S, dtype=np.int32) * L)[None, :]
-    CTC, CTD, CID, CNCH, CCH, CRK = S, S + 1, S + 2, S + 3, S + 4, S + 4 + N
 
     @jax.jit
     def run(gdurs, glen):
+        size_onehot = jnp.asarray(sa.size_onehot)[None]     # (1, N, S)
+        childmask = jnp.asarray(sa.childmask)[None]         # (1, N, N)
+        childrank = jnp.asarray(sa.childrank)[None]         # (1, N, N)
+        tc_n = jnp.asarray(sa.tc)
+        td_n = jnp.asarray(sa.td)
+        nch_n = jnp.asarray(sa.nch)
+        sizebase = jnp.asarray(np.arange(S, dtype=np.int32) * L)[None, :]
         gflat = gdurs.reshape(C, S * L)
 
         def body(st, _):
@@ -990,15 +1002,16 @@ def _phase_a_program(sa: _SpecArrays, C: int, L: int) -> Callable:
             candm = theap == tmin
             seqm = jnp.where(candm, tseq, _BIG_SEQ)
             sel = candm & (seqm == seqm.min(1, keepdims=True))
-            self_f = sel.astype(jnp.float64)
-            p = self_f @ proj
-            sel_s = p[:, :S] > 0.5
-            tc = p[:, CTC:CTC + 1]
-            td = p[:, CTD:CTD + 1]
-            nid = p[:, CID:CID + 1]
-            nch = p[:, CNCH:CNCH + 1]
-            chmask = p[:, CCH:CCH + N] > 0.5
-            chrank = p[:, CRK:CRK + N]
+            # the popped node's constants: sel is one-hot on live rows
+            sel3 = sel[:, :, None]
+            sel_s = (sel3 & size_onehot).any(1)
+            tc = jnp.where(sel, tc_n, 0.0).sum(1, keepdims=True)
+            td = jnp.where(sel, td_n, 0.0).sum(1, keepdims=True)
+            nch = jnp.where(sel, nch_n, 0).sum(1, keepdims=True,
+                                                dtype=jnp.int32)
+            chmask = (sel3 & childmask).any(1)
+            chrank = jnp.where(sel3, childrank, 0).sum(1, dtype=jnp.int32)
+            nid = jnp.argmax(sel, 1).astype(jnp.int32)
 
             alive = jnp.isfinite(tmin)
             place = (sel_s & (cursor < glen)).any(1, keepdims=True) & alive
@@ -1019,14 +1032,10 @@ def _phase_a_program(sa: _SpecArrays, C: int, L: int) -> Callable:
             theap = jnp.where(sel, jnp.where(place, end, INF), theap)
             theap = jnp.where(repart & chmask, tmin, theap)
             tseq = jnp.where(sel & place, seqctr, tseq)
-            tseq = jnp.where(
-                repart & chmask, seqctr + chrank.astype(jnp.int32), tseq
-            )
-            seqctr = seqctr + jnp.where(
-                place, 1, jnp.where(repart, nch.astype(jnp.int32), 0)
-            )
+            tseq = jnp.where(repart & chmask, seqctr + chrank, tseq)
+            seqctr = seqctr + jnp.where(place, 1, jnp.where(repart, nch, 0))
             has = has | (sel & create)
-            pos = jnp.where(sel, ccnt, 0).sum(1, keepdims=True)
+            pos = jnp.where(sel, ccnt, 0).sum(1, dtype=jnp.int32)
             ccnt = ccnt + (sel & place).astype(jnp.int32)
             adv = sel_s & place
             cursor = cursor + adv.astype(jnp.int32)
@@ -1034,7 +1043,7 @@ def _phase_a_program(sa: _SpecArrays, C: int, L: int) -> Callable:
             # a (C, S) take_along_axis on the CPU backend)
             flatidx = jnp.where(
                 sel_s, sizebase + jnp.minimum(cursor, L - 1), 0
-            ).sum(1)
+            ).sum(1, dtype=jnp.int32)
             gd = jax.vmap(
                 lambda row, i: jax.lax.dynamic_slice(row, (i,), (1,))[0]
             )(gflat, flatidx)
@@ -1042,17 +1051,17 @@ def _phase_a_program(sa: _SpecArrays, C: int, L: int) -> Callable:
             rem = rem - place.astype(jnp.int32)
             pl = place[:, 0]
             rec = (
-                jnp.where(pl, nid[:, 0], -1.0),
+                jnp.where(pl, nid, -1),
                 jnp.where(pl, d[:, 0], 0.0),
-                jnp.where(pl, pos[:, 0].astype(jnp.float64), 0.0),
+                jnp.where(pl, pos, 0),
             )
             return (theap, tseq, seqctr, cursor, dnext, re, has, rem,
                     ccnt), rec
 
         st = (
-            jnp.broadcast_to(theap0, (C, N)),
-            jnp.broadcast_to(tseq0, (C, N)),
-            jnp.full((C, 1), seq0, jnp.int32),
+            jnp.broadcast_to(sa.theap0, (C, N)),
+            jnp.broadcast_to(sa.tseq0, (C, N)),
+            jnp.full((C, 1), sa.seq0, jnp.int32),
             jnp.zeros((C, S), jnp.int32),
             gdurs[:, :, 0],
             jnp.zeros((C, 1)),
@@ -1060,30 +1069,132 @@ def _phase_a_program(sa: _SpecArrays, C: int, L: int) -> Callable:
             glen.sum(1, keepdims=True),
             jnp.zeros((C, N), jnp.int32),
         )
-        return jax.lax.scan(body, st, None, length=T)[1]
+        st, (nid, dur, pos) = jax.lax.scan(body, st, None, length=T)
+        # scatter the placements into per-node chains (node index N, for
+        # steps that placed nothing, falls outside and is dropped)
+        cols = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (T, C))
+        node = jnp.where(nid >= 0, nid, N)
+        chain_durs = jnp.zeros((C, N, L)).at[cols, node, pos].set(
+            dur, mode="drop"
+        )
+        return nid, chain_durs, st[-1]
 
-    _PROGRAM_CACHE.put(sa.spec, run, (C, L))
+    _PROGRAM_CACHE.put(sa.spec, run, ("phase_a", C, L))
     return run
+
+
+def _chains_program(spec: DeviceSpec, C: int, L: int) -> Callable:
+    """Jitted :func:`~repro.core.timing.chains_makespan_batch`: the
+    replay-semantics event walk over ``C`` candidates' ``(C, N, L)``
+    zero-padded duration chains and ``(C, N)`` lengths, returning the
+    ``(C,)`` makespans bit-identical to ``chains_makespan`` per candidate.
+
+    The tree is tiny, so the event queue holds at most one pending event
+    per node and a pop is a masked ``(when, seq)`` argmin over the node
+    axis; every node contributes at most one visit and one done pop, so
+    ``2 * N`` steps drain every walk (trailing steps are masked no-ops).
+    Bit-exactness is by construction: the chain fold is a sequential loop
+    of float64 additions (a left fold, never an associative scan, whose
+    re-association would change roundings; the zero padding adds exact
+    ``+0.0``), and the popped node's values are read by gathers and
+    masked selects, which do no arithmetic.  Call, trace and run it
+    inside ``jax.enable_x64(True)``.
+    """
+    cached = _PROGRAM_CACHE.get(spec, ("chains", C, L))
+    if cached is not None:
+        return cached
+    jax, jnp = _jax_modules()
+    (tc, td, childmask, descmask, root_idx, grp_idx,
+     n_groups) = _batch_spec_arrays(spec)
+    N = len(spec.nodes)
+    BIG = _BIG_SEQ
+    INF = np.inf
+
+    @jax.jit
+    def walk(durs, lens):
+        tc_n, td_n = jnp.asarray(tc), jnp.asarray(td)
+        child = jnp.asarray(childmask)
+        grp = jnp.asarray(grp_idx)
+        active = lens > 0                                       # (C, N)
+        # sub_act[c, a]: an active node in subtree(a); goflag[c, p]: a
+        # child of p has an active subtree
+        sub_act = (active[:, None, :] & jnp.asarray(descmask)[None]).any(2)
+        goflag = (sub_act[:, None, :] & child[None]).any(2)
+        tevt = jnp.full((C, N), INF)
+        sevt = jnp.full((C, N), BIG, jnp.int32)
+        wevt = jnp.zeros((C, N), jnp.int32)                     # 0 visit, 1 done
+        seqctr = jnp.zeros((C,), jnp.int32)
+        for i in root_idx:  # roots pushed in spec order, seq 0, 1, ...
+            pushed = sub_act[:, i]
+            tevt = tevt.at[:, i].set(jnp.where(pushed, 0.0, INF))
+            sevt = sevt.at[:, i].set(jnp.where(pushed, seqctr, BIG))
+            seqctr = seqctr + pushed.astype(jnp.int32)
+        iota_n = np.arange(N)[None, :]
+        iota_g = np.arange(n_groups)[None, :]
+
+        def pick(a, n_star):
+            return jnp.take_along_axis(a, n_star[:, None], 1)[:, 0]
+
+        def step(_, carry):
+            tevt, sevt, wevt, seqctr, re, mk = carry
+            rows = jnp.isfinite(tevt).any(1)
+            when = tevt.min(1)
+            cand = tevt == when[:, None]
+            seqm = jnp.where(cand, sevt, BIG)
+            sel = cand & (seqm == seqm.min(1)[:, None]) & rows[:, None]
+            n_star = jnp.argmax(sel, 1)
+            onehot = iota_n == n_star[:, None]
+            oh_g = iota_g == grp[n_star][:, None]
+            re_cur = jnp.where(oh_g, re, 0.0).sum(1)
+            what = pick(wevt, n_star)
+            act = pick(active, n_star)
+            m_visit = rows & (what == 0)
+            m_va = m_visit & act
+            m_done = rows & (what == 1)
+
+            # visit of an active node: creation charge + exact chain fold
+            t0 = jnp.maximum(re_cur, when) + tc_n[n_star]
+            chosen = jnp.take_along_axis(
+                durs, n_star[:, None, None], 1
+            )[:, 0]                                             # (C, L)
+            flen = jnp.where(m_va, pick(lens, n_star), 0).max()
+            end = jax.lax.fori_loop(
+                0, flen, lambda l, t: t + chosen[:, l], t0
+            )
+            re = jnp.where(oh_g & m_va[:, None], t0[:, None], re)
+            mk = jnp.where(m_va & (end > mk), end, mk)
+            # visit -> done event in place (chain end if active, else when)
+            upd_v = onehot & m_visit[:, None]
+            tevt = jnp.where(upd_v, jnp.where(m_va, end, when)[:, None],
+                             tevt)
+            wevt = jnp.where(upd_v, 1, wevt)
+            sevt = jnp.where(upd_v, seqctr[:, None], sevt)
+            seqctr = seqctr + m_visit.astype(jnp.int32)
+
+            # done: destroy (active node, active subtree remains) + children
+            m_dgo = m_done & pick(goflag, n_star)
+            m_destroy = m_dgo & act
+            re_d = jnp.maximum(re_cur, when) + td_n[n_star]
+            re = jnp.where(oh_g & m_destroy[:, None], re_d[:, None], re)
+            tevt = jnp.where(onehot & m_done[:, None], INF, tevt)
+            push = child[n_star] & sub_act & m_dgo[:, None]
+            rank = jnp.cumsum(push, 1, dtype=jnp.int32) - 1
+            tevt = jnp.where(push, when[:, None], tevt)
+            wevt = jnp.where(push, 0, wevt)
+            sevt = jnp.where(push, seqctr[:, None] + rank, sevt)
+            seqctr = seqctr + push.sum(1, dtype=jnp.int32)
+            return tevt, sevt, wevt, seqctr, re, mk
+
+        carry = (tevt, sevt, wevt, seqctr, jnp.zeros((C, n_groups)),
+                 jnp.zeros((C,)))
+        return jax.lax.fori_loop(0, 2 * N, step, carry)[5]
+
+    _PROGRAM_CACHE.put(spec, walk, ("chains", C, L))
+    return walk
 
 
 def _pow2(x: int) -> int:
     return 1 << max(1, (x - 1).bit_length())
-
-
-def _score_chains_batch(spec, chain_durs, chain_len):
-    """Batched chain scoring backend: the fused Pallas kernel on
-    accelerator backends (``repro.kernels.chains_makespan``), the numpy
-    lockstep otherwise.  Both are pinned bit-identical per candidate to
-    :func:`chains_makespan`, so the dispatch cannot change a winner."""
-    try:
-        from repro.kernels.chains_makespan import ops as _cm_ops
-    except ImportError:  # pragma: no cover - kernels package stripped
-        _cm_ops = None
-    if _cm_ops is not None and _cm_ops.pallas_usable():
-        return _cm_ops.chains_makespan_batch_pallas(
-            spec, chain_durs, chain_len
-        )
-    return chains_makespan_batch(spec, chain_durs, chain_len)
 
 
 @register_evaluator("vectorized")
@@ -1091,31 +1202,28 @@ class VectorizedEvaluator(FamilyEvaluator):
     """Chunked array-program scorer (module docstring has the design).
 
     Scores candidates in growing chunks through the jitted lockstep and
-    the batched chain scorer; the shared :func:`_winner_scan` then walks
-    the scores with the same prune/incumbent comparisons as the
-    sequential path, so extra chunk-tail candidates cost time but never
-    change the selection.  Only the winner's assignment is materialised
-    (task ids resolved from the membership row + recorded pop sequence).
+    the jitted chain walk, both on jax's default device; the shared
+    :func:`_winner_scan` then walks the scores with the same
+    prune/incumbent comparisons as the sequential path, so extra
+    chunk-tail candidates cost time but never change the selection.
+    Only the winner's assignment is materialised (task ids resolved from
+    the membership row + recorded pop sequence).
+
+    Off the CPU backend every scored chunk and the winner's assignment
+    are checked against the sequential pipeline on the host, and the
+    first difference raises :class:`DeviceMismatchError`: a device whose
+    float64 is not IEEE (the TPU emulates it) must not pick a different
+    winner, and the evaluator does not fall back to another path.
     """
 
     def evaluate(self, tasks, spec, first, deltas, config):
         if not HAVE_JAX:
-            global _WARNED_NO_JAX
-            if not _WARNED_NO_JAX:
-                _WARNED_NO_JAX = True
-                import warnings
-
-                warnings.warn(
-                    "evaluator='vectorized' requested but jax is not "
-                    "importable; scoring sequentially (results are "
-                    "identical, timings are not)",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-            return EVALUATORS["sequential"].evaluate(
-                tasks, spec, first, deltas, config
+            raise RuntimeError(
+                "evaluator='vectorized' needs jax, which is not installed; "
+                "use evaluator='sequential' or 'auto'"
             )
-        _, jnp, enable_x64 = _jax_modules()
+        jax, jnp = _jax_modules()
+        platform = _platform()
         n = len(tasks)
         F = len(deltas) + 1
         sa = _spec_eval_arrays(spec)
@@ -1192,25 +1300,23 @@ class VectorizedEvaluator(FamilyEvaluator):
                 member[ro, po] = False
                 member[rn, pn] = True
             # constants, tracing and execution must all sit inside the
-            # x64 scope, or the program silently truncates to float32
-            with enable_x64():
+            # x64 scope, or the programs silently truncate to float32
+            with jax.enable_x64(True):
                 run = _phase_a_program(sa, Cb, L)
-                nid_j, dur_j, pos_j = run(jnp.asarray(gdurs), jnp.asarray(glen))
-            t_used = n + N
-            nid = np.asarray(nid_j)[:t_used].astype(np.int64)   # (T, Cb)
-            dv = np.asarray(dur_j)[:t_used]
-            cpos = np.asarray(pos_j)[:t_used].astype(np.int64)
-            # per-node duration chains -> batched replay-semantics scoring
-            # (the program already recorded each pop's chain position)
-            valid = nid >= 0
-            cols = np.broadcast_to(np.arange(Cb), nid.shape)[valid]
-            nodes = nid[valid]
-            grp = cols * N + nodes
-            chain_len = np.bincount(grp, minlength=Cb * N).reshape(Cb, N)
-            Lc = max(1, int(chain_len.max()))
-            cd = np.zeros((Cb, N, Lc))
-            cd[cols, nodes, cpos[valid]] = dv[valid]
-            scores = _score_chains_batch(spec, cd, chain_len)
+                walk = _chains_program(spec, Cb, L)
+                nid_j, cd_j, cl_j = run(jnp.asarray(gdurs), jnp.asarray(glen))
+                scores = np.asarray(walk(cd_j, cl_j))
+                nid = np.asarray(nid_j)[: n + N]              # (T, Cb)
+            if platform != "cpu":
+                ref = _chunk_scores((tasks, spec, first, deltas, i0,
+                                     i0 + count))
+                for k in range(count):
+                    if scores[k] != ref[k]:
+                        raise DeviceMismatchError(
+                            f"evaluator='vectorized' on {platform}: family "
+                            f"candidate {i0 + k} scores {float(scores[k])!r} "
+                            f"on the device but {ref[k]!r} on the host"
+                        )
             for k in range(count):
                 state["scores"][i0 + k] = float(scores[k])
             state["chunk"] = (i0, mem0, nid)
@@ -1240,6 +1346,14 @@ class VectorizedEvaluator(FamilyEvaluator):
         winner_alloc = list(first)
         for j, s_new in deltas[:win]:
             winner_alloc[j] = s_new
+        if platform != "cpu":
+            host = LPTGroups(tasks, tuple(winner_alloc), spec).schedule()
+            if host.node_tasks != assignment.node_tasks:
+                raise DeviceMismatchError(
+                    f"evaluator='vectorized' on {platform}: the winning "
+                    f"family candidate {win} is placed differently on the "
+                    f"device than on the host"
+                )
         return FamilyWinner(
             makespan, win, assignment, tuple(winner_alloc), evaluated
         )
@@ -1281,6 +1395,7 @@ __all__ = [
     "AUTO_MIN_TASKS",
     "AUTO_MIN_TASKS_INCREMENTAL",
     "AUTO_MIN_TASKS_UNPRUNED",
+    "DeviceMismatchError",
     "EVALUATORS",
     "FamilyEvaluator",
     "FamilyWinner",

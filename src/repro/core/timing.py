@@ -67,6 +67,20 @@ from repro.core.repartition import (
 )
 
 
+def left_fold(start: float, values) -> float:
+    """``((start + v0) + v1) + ...``, added strictly left to right.
+
+    This is the summation rule of every timing path: replay, the timing
+    engine, ``_fastsim.c`` and the vectorized device programs all fold
+    chains this way, so their results agree bit for bit.  ``sum()`` is
+    not this fold: since Python 3.12 it compensates float rounding
+    (Neumaier), which can differ in the last bit.
+    """
+    for v in values:
+        start += v
+    return start
+
+
 def _lpt_insert_pos(lst: list[int], tid: int, tasks, size: int) -> int:
     """Insert position keeping ``lst`` LPT-ordered (desc by duration), the
     invariant phase 3 / §4.3 maintain on every node's task list."""
@@ -662,8 +676,7 @@ class TimingEngine(ChainState):
                         end += d
                     chain_fold[key] = (t, ver, end, mass)
                 else:
-                    # sum() is the same left fold replay performs, in C
-                    end = sum(durs[key], t)
+                    end = left_fold(t, durs[key])
                     mass = None
                     chain_fold[key] = (t, ver, end, None)
                 node_t0[key] = t
@@ -763,8 +776,7 @@ class TimingEngine(ChainState):
                         mass += end
                         end += d
                 else:
-                    # sum() is the same left fold replay performs, in C
-                    end = sum(ds, t)
+                    end = left_fold(t, ds)
                     mass = None
                 chain_fold[key] = (t, ver, end, mass)
             if need_mass:
@@ -916,8 +928,7 @@ def chains_makespan(
                     r = when
                 r += t_create[node.size]
                 rc_end[g] = r
-                # sum() is the same left fold replay performs, in C
-                t = sum(node_durs[key], r)
+                t = left_fold(r, node_durs[key])
                 if t > makespan:
                     makespan = t
                 heappush(heap, (t, seq, 1, node))
@@ -1236,5 +1247,6 @@ __all__ = [
     "ReplayEngine",
     "chains_makespan",
     "chains_makespan_batch",
+    "left_fold",
     "make_engine",
 ]

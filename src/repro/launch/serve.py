@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get, get_smoke
-from repro.launch.mesh import mesh_shape_dict
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh, mesh_shape_dict
 from repro.models.config import ShapeConfig
 from repro.models.model import build_model
 from repro.parallel.sharding import make_rules
@@ -36,7 +37,7 @@ def serve(
     model = build_model(cfg)
     if mesh is None:
         n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
     rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
     shape = ShapeConfig("serve", prompt_len, batch, "prefill")
 
@@ -55,14 +56,18 @@ def serve(
         decode_fn = jax.jit(dec.fn, in_shardings=dec.in_shardings,
                             out_shardings=dec.out_shardings,
                             donate_argnums=dec.donate_argnums)
-        params = model.init(jax.random.key(0))
+        # weights are made in place on the mesh: an eager init would put
+        # every job's full copy on the first device first
+        params = jax.jit(model.init, out_shardings=pre.in_shardings[0])(
+            jax.random.key(0)
+        )
         batch_in = {"tokens": jnp.asarray(prompts)}
         if cfg.is_encoder_decoder:
             batch_in["frames"] = jnp.zeros(
                 (batch, cfg.encoder_frames, cfg.d_model), jnp.bfloat16
             )
         t0 = time.time()
-        logits, cache = prefill_fn(params, batch_in)
+        logits, cache = jax.block_until_ready(prefill_fn(params, batch_in))
         prefill_s = time.time() - t0
 
         key = jax.random.key(seed)
@@ -93,6 +98,7 @@ def serve(
         "prefill_s": prefill_s,
         "decode_s": decode_s,
         "tokens_per_s": tput,
+        "device_ids": sorted(d.id for d in logits.sharding.device_set),
     }
 
 
@@ -104,6 +110,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
           gen=args.gen, smoke=not args.full)
 

@@ -21,8 +21,9 @@ import numpy as np
 
 from repro import ckpt as ckpt_lib
 from repro.configs import get, get_smoke
+from repro.launch.compile_cache import use_compile_cache
 from repro.data import SyntheticTokens
-from repro.launch.mesh import mesh_shape_dict
+from repro.launch.mesh import make_mesh, mesh_shape_dict
 from repro.models.config import ShapeConfig, input_specs
 from repro.models.model import build_model
 from repro.optim import wsd_schedule
@@ -54,7 +55,7 @@ def train(
 
     if mesh is None:
         n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
     rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
 
     bundle = make_train_step(
@@ -135,6 +136,7 @@ def main() -> None:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    use_compile_cache()
     out = train(
         args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
         smoke=not args.full, ckpt_dir=args.ckpt_dir,

@@ -15,6 +15,7 @@ built only when a mesh is supplied.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Mapping, Sequence
 
 import jax
@@ -155,7 +156,16 @@ def make_rules(
 # no-op outside a mesh context so smoke tests on 1 CPU device do not shard.
 # ---------------------------------------------------------------------------
 
-_ACTIVE_RULES: list[ShardingRules | None] = [None]
+# per thread: the live executor traces jobs for disjoint instances
+# concurrently, one thread per instance
+_ACTIVE = threading.local()
+
+
+def _active_rules() -> list[ShardingRules | None]:
+    stack = getattr(_ACTIVE, "rules", None)
+    if stack is None:
+        stack = _ACTIVE.rules = [None]
+    return stack
 
 
 class use_rules:
@@ -165,18 +175,18 @@ class use_rules:
         self.rules = rules
 
     def __enter__(self):
-        _ACTIVE_RULES.append(self.rules)
+        _active_rules().append(self.rules)
         return self.rules
 
     def __exit__(self, *exc):
-        _ACTIVE_RULES.pop()
+        _active_rules().pop()
         return False
 
 
 def logical(x: jax.Array, *names: str | None) -> jax.Array:
     """Apply a with_sharding_constraint for the active rule table (no-op
     when no rules are installed, e.g. single-device smoke tests)."""
-    rules = _ACTIVE_RULES[-1]
+    rules = _active_rules()[-1]
     if rules is None:
         return x
     spec = rules.spec(*names)

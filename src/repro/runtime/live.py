@@ -7,9 +7,10 @@ it, and recurses into its children in separate threads, so tasks on
 disjoint instances run concurrently.  Wall-clock task start/end offsets
 are reported for the Table-3-style sim-vs-real comparison.
 
-Tasks here are real work: a few steps of a smoke-config model on the
-instance's devices (CPU devices in this container — same code path as a
-pod).  One slice maps to ``len(devices) // n_slices`` devices.
+Tasks here are real work: a model job on the instance's devices.  One
+slice maps to ``len(devices) // n_slices`` consecutive devices, so the
+device count must be a multiple of the spec's slice count; any other
+count is refused rather than leaving instances without devices.
 """
 
 from __future__ import annotations
@@ -48,10 +49,23 @@ def run_live(
       spec: the device spec the assignment was built for.
       task_fn: ``task_fn(task_id, mesh) -> payload dict`` — the actual work.
       devices: flat device list (default: all jax.devices()).
+
+    Raises:
+      ValueError: the spec's slices do not map onto ``devices`` (their
+        count is not a positive multiple of ``spec.n_slices``).
+      The first exception a task raised, once every other instance has
+        finished; the failed instance's subtree does not run.
     """
     devices = list(devices if devices is not None else jax.devices())
-    per_slice = max(len(devices) // spec.n_slices, 1)
+    if not devices or len(devices) % spec.n_slices:
+        raise ValueError(
+            f"{spec.name}'s {spec.n_slices} slices do not map onto "
+            f"{len(devices)} devices: each slice needs the same whole "
+            f"number of devices"
+        )
+    per_slice = len(devices) // spec.n_slices
     records: list[LiveRecord] = []
+    errors: list[BaseException] = []
     lock = threading.Lock()
     init_time = time.perf_counter()
 
@@ -65,34 +79,37 @@ def run_live(
 
     def execute_tree(node: InstanceNode) -> None:
         tids = assignment.node_tasks.get(node.key, [])
-        if tids:
-            devs = devices_of(node)
-            n = len(devs)
-            mesh = make_submesh(devs, data=n, model=1)
-            for tid in tids:
-                t0 = time.perf_counter() - init_time
-                payload = task_fn(tid, mesh)
-                t1 = time.perf_counter() - init_time
-                with lock:
-                    records.append(LiveRecord(
-                        tid, repr(node), t0, t1, payload
-                    ))
+        try:
+            if tids:
+                devs = devices_of(node)
+                n = len(devs)
+                mesh = make_submesh(devs, data=n, model=1)
+                for tid in tids:
+                    t0 = time.perf_counter() - init_time
+                    payload = task_fn(tid, mesh)
+                    t1 = time.perf_counter() - init_time
+                    with lock:
+                        records.append(LiveRecord(
+                            tid, repr(node), t0, t1, payload
+                        ))
+        except BaseException as e:  # re-raised by run_live after the joins
+            with lock:
+                errors.append(e)
+            return  # a failed instance is not repartitioned
+        run_all(node.children)
+
+    def run_all(nodes) -> None:
         threads = [
-            threading.Thread(target=execute_tree, args=(child,))
-            for child in node.children
+            threading.Thread(target=execute_tree, args=(node,))
+            for node in nodes
         ]
         for t in threads:
             t.start()
         for t in threads:
             t.join()
 
-    roots = [
-        threading.Thread(target=execute_tree, args=(root,))
-        for root in spec.roots
-    ]
-    for t in roots:
-        t.start()
-    for t in roots:
-        t.join()
+    run_all(spec.roots)
+    if errors:
+        raise errors[0]
     records.sort(key=lambda r: r.end)
     return records

@@ -48,14 +48,14 @@ import sys; sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 import numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel.compression import ring_allreduce_int8
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 x = jax.random.normal(jax.random.key(0), (8, 64), jnp.float32)
 
-@partial(shard_map, mesh=mesh, in_specs=P("pod", None),
+@partial(jax.shard_map, mesh=mesh, in_specs=P("pod", None),
          out_specs=P("pod", None))
 def ring(v):
     flat = v.reshape(-1)

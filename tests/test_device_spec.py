@@ -3,8 +3,18 @@
 import pytest
 
 from repro.core.device_spec import (
-    A30, A100, H100, TPU_POD_256, TPU_SUPERPOD_512, multi_gpu,
+    A30, A100, H100, TPU_POD_256, TPU_SUPERPOD_512, V5E_1, V5E_2X2, multi_gpu,
 )
+
+
+def test_v5e_host_specs():
+    """One chip per slice: a single-chip instance, and the 2x2 host as
+    4 -> 2+2 -> 1+1+1+1."""
+    assert V5E_1.n_slices == 1 and V5E_1.sizes == (1,)
+    assert len(V5E_1.valid_partitions) == 1
+    assert V5E_2X2.n_slices == 4 and V5E_2X2.sizes == (1, 2, 4)
+    assert sorted(n.size for n in V5E_2X2.nodes) == [1, 1, 1, 1, 2, 2, 4]
+    assert len(V5E_2X2.valid_partitions) == 5
 
 
 def test_partition_counts_match_paper_fig1():

@@ -145,7 +145,9 @@ def test_family_areas_match_stepwise_fold():
     tasks = make_tasks(30, spec, seed=11)
     first, deltas = allocation_family_deltas(tasks, spec)
     areas = family_areas(tasks, first, deltas)
-    area = sum(s * t.times[s] for t, s in zip(tasks, first))
+    area = 0.0
+    for t, s in zip(tasks, first):  # left fold, not sum()'s compensation
+        area += s * t.times[s]
     alloc = list(first)
     assert areas[0] == area
     for k, (j, s_new) in enumerate(deltas):
@@ -217,6 +219,65 @@ def test_resolve_evaluator_dispatch():
 def test_empty_batch():
     res = schedule_batch([], A100, SchedulerConfig(evaluator="vectorized"))
     assert res.makespan == 0.0 and res.family_size == 1
+
+
+# -- vectorized off the CPU backend -----------------------------------------
+# A TPU's float64 is emulated, so off the CPU the vectorized evaluator
+# checks every device score against the host.  These tests steer that path
+# on the CPU by reporting another backend.
+
+
+@pytest.fixture
+def off_cpu(monkeypatch):
+    import repro.core.family_eval as fe
+
+    monkeypatch.setattr(fe, "_platform", lambda: "tpu")
+    return fe
+
+
+@pytest.mark.parametrize("prune", [True, False])
+def test_vectorized_off_cpu_checks_and_matches(off_cpu, prune):
+    tasks = make_tasks(40, A100, seed=9)
+    rs = schedule_batch(tasks, A100, SchedulerConfig(
+        evaluator="sequential", prune=prune, refine=False))
+    rv = schedule_batch(tasks, A100, SchedulerConfig(
+        evaluator="vectorized", prune=prune, refine=False))
+    assert_identical(rs, rv)
+
+
+def test_vectorized_off_cpu_refuses_a_device_difference(off_cpu,
+                                                         monkeypatch):
+    """One ulp of difference on the device is refused, naming the first
+    candidate that differs; no winner is returned."""
+    exact = off_cpu._chains_program
+
+    def one_ulp_late(spec, C, L):
+        walk = exact(spec, C, L)
+        return lambda d, n: np.nextafter(np.asarray(walk(d, n)), np.inf)
+
+    monkeypatch.setattr(off_cpu, "_chains_program", one_ulp_late)
+    tasks = make_tasks(40, A100, seed=9)
+    with pytest.raises(off_cpu.DeviceMismatchError,
+                       match="on tpu: family candidate 0 scores"):
+        schedule_batch(tasks, A100, SchedulerConfig(
+            evaluator="vectorized", prune=False, refine=False))
+
+
+def test_auto_never_picks_vectorized_off_cpu(off_cpu, monkeypatch):
+    from repro.core import fastsim
+
+    monkeypatch.setattr(fastsim, "available", lambda: False)
+    auto = SchedulerConfig(evaluator="auto", prune=False)
+    assert resolve_evaluator(auto, 10**6, 10**6) == "sequential"
+
+
+def test_vectorized_without_jax_raises(monkeypatch):
+    import repro.core.family_eval as fe
+
+    monkeypatch.setattr(fe, "HAVE_JAX", False)
+    with pytest.raises(RuntimeError, match="needs jax"):
+        schedule_batch(make_tasks(10, A100), A100,
+                       SchedulerConfig(evaluator="vectorized"))
 
 
 # -- incremental delta-replay evaluator -------------------------------------

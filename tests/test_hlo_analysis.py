@@ -17,8 +17,9 @@ import sys; sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = make_mesh((4, 4), ("data", "model"))
 D, F, L = 256, 512, 8
 
 def loss(params, x):

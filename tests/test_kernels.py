@@ -3,6 +3,7 @@ sweeping shapes, dtypes and feature flags."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels.decode_attention.kernel import decode_attention
@@ -135,19 +136,26 @@ def test_slstm_cell_vs_ref(b, t, h, d, chunk, dtype):
     assert float(err) < (5e-2 if dtype == jnp.bfloat16 else 1e-4)
 
 
+def _device_chain_scores(spec, durs, lens):
+    from repro.core.family_eval import _chains_program
+
+    C, _, L = durs.shape
+    with jax.enable_x64(True):
+        walk = _chains_program(spec, C, L)
+        return np.asarray(walk(jnp.asarray(durs), jnp.asarray(lens)))
+
+
 @pytest.mark.parametrize("spec_name", ["A30", "A100", "TPU"])
 @pytest.mark.parametrize("C,L,integer", [
     (1, 1, False), (3, 7, True), (8, 21, False), (13, 40, True),
 ])
 def test_chains_makespan_vs_ref_bit_exact(spec_name, C, L, integer):
-    """Unlike the model kernels, the scheduler kernel's contract is
-    bit-exactness, not a tolerance: phase-2 winner selection breaks EPS
-    ties by index, so a single ulp could flip a winner."""
-    import numpy as np
-
+    """The vectorized evaluator's jitted chain walk against the numpy
+    reference.  Unlike the model kernels, the contract is bit-exactness,
+    not a tolerance: phase-2 winner selection breaks EPS ties by index,
+    so a single ulp could flip a winner."""
     from repro.core.device_spec import A30, A100, TPU_POD_256
-    from repro.kernels.chains_makespan.ops import chains_makespan_batch_pallas
-    from repro.kernels.chains_makespan.ref import chains_makespan_batch_ref
+    from repro.core.timing import chains_makespan_batch
 
     spec = {"A30": A30, "A100": A100, "TPU": TPU_POD_256}[spec_name]
     N = len(spec.nodes)
@@ -162,20 +170,17 @@ def test_chains_makespan_vs_ref_bit_exact(spec_name, C, L, integer):
             if integer:  # tie-dense chains stress the (when, seq) order
                 vals = np.floor(vals * 2.0) / 2.0
             durs[c, j, :k] = vals
-    ref = chains_makespan_batch_ref(spec, durs, lens)
-    out = chains_makespan_batch_pallas(spec, durs, lens, interpret=True)
+    ref = chains_makespan_batch(spec, durs, lens)
+    out = _device_chain_scores(spec, durs, lens)
+    assert out.dtype == np.float64
     assert np.array_equal(ref, out)
 
 
-def test_chains_makespan_pallas_empty_batch():
-    import numpy as np
-
+def test_chains_makespan_empty_candidates():
     from repro.core.device_spec import A100
-    from repro.kernels.chains_makespan.ops import chains_makespan_batch_pallas
 
     N = len(A100.nodes)
-    out = chains_makespan_batch_pallas(
-        A100, np.zeros((0, N, 1)), np.zeros((0, N), dtype=np.int32),
-        interpret=True,
+    out = _device_chain_scores(
+        A100, np.zeros((8, N, 4)), np.zeros((8, N), dtype=np.int32)
     )
-    assert out.shape == (0,)
+    assert out.tolist() == [0.0] * 8

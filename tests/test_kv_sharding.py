@@ -10,14 +10,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 from repro.configs import SMOKES
-from repro.launch.mesh import mesh_shape_dict
+from repro.launch.mesh import make_mesh, mesh_shape_dict
 from repro.models.config import ShapeConfig
 from repro.models.model import build_model
 from repro.parallel.sharding import make_rules
 from repro.parallel.steps import make_decode_step, make_prefill_step
 
 cfg = SMOKES["qwen2.5-3b"]         # kv=2: cannot shard over a 4-way axis
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False, batch_size=2)
 assert rules.rules["kv_heads"] == ()
 assert rules.rules["kv_len"] == ("model",)

@@ -4,6 +4,8 @@ concurrent across disjoint instances (8 virtual devices, subprocess)."""
 import subprocess
 import sys
 
+import pytest
+
 _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -57,6 +59,25 @@ for a, b in itertools.combinations(spans, 2):
 assert overlap, "disjoint instances never ran concurrently"
 print("LIVE_OK")
 """
+
+
+@pytest.mark.parametrize("spec_name,n_devices", [
+    ("A100", 4), ("A30", 6), ("TPU_POD_256", 4), ("V5E_2X2", 0),
+])
+def test_live_executor_refuses_unmappable_devices(spec_name, n_devices):
+    """Each slice needs the same whole number of devices; nothing runs
+    when the spec's slices do not divide the device list."""
+    from repro.core.device_spec import SPECS
+    from repro.core.repartition import Assignment
+    from repro.runtime.live import run_live
+
+    spec = SPECS[spec_name]
+    ran = []
+    with pytest.raises(ValueError, match="do not map onto"):
+        run_live(Assignment(spec, {}, {}), spec,
+                 lambda tid, mesh: ran.append(tid),
+                 devices=[object()] * n_devices)
+    assert ran == []
 
 
 def test_live_executor_runs_far_tree_concurrently():

@@ -31,6 +31,28 @@ def test_cost_model_times_monotone_non_increasing():
             assert all(task.times[s] > 0 for s in sizes)
 
 
+@pytest.mark.parametrize("chips", [1, 2, 4, 8, 16])
+def test_cost_model_below_one_model_axis(chips):
+    """On a 1-16 chip instance the model axis shrinks to the chips there
+    are and data parallelism stays 1: times are finite, and more chips
+    are never slower than one."""
+    cfg = ARCHS["gemma-2b"]
+    for sh in ("train_4k", "decode_32k"):
+        t = step_time(cfg, SHAPES[sh], 1, chips_per_slice=chips)
+        assert 0 < t < float("inf"), (chips, sh)
+        if chips > 1:
+            assert t <= step_time(cfg, SHAPES[sh], 1, chips_per_slice=1)
+
+
+def test_cost_model_profiles_v5e_host():
+    from repro.core.device_spec import V5E_2X2
+
+    job = Job(0, ARCHS["gemma-2b"], SHAPES["decode_32k"], steps=10)
+    task = job_to_task(job, V5E_2X2)
+    assert sorted(task.times) == [1, 2, 4]
+    assert task.check_time_monotone()
+
+
 def test_cost_model_spill_makes_work_non_monotone():
     """qwen1.5-110b training cannot fit 32 chips -> super-linear speedup
     regime (the TPU analogue of paper §2.4)."""

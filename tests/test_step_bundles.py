@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import SMOKES
-from repro.launch.mesh import mesh_shape_dict
+from repro.launch.mesh import make_mesh, mesh_shape_dict
 from repro.models.config import ShapeConfig, input_specs
 from repro.models.model import build_model
 from repro.parallel.sharding import make_rules
@@ -23,7 +23,7 @@ from repro.parallel.steps import (
 def test_train_bundle_runs(name):
     cfg = SMOKES[name]
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
     shape = ShapeConfig("t", 32, 2, "train")
     bundle = make_train_step(model, rules, mesh, shape)
@@ -51,7 +51,7 @@ def test_train_bundle_runs(name):
 def test_prefill_decode_bundles_run(name):
     cfg = SMOKES[name]
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
     shape_p = ShapeConfig("p", 32, 2, "prefill")
     shape_d = ShapeConfig("d", 32, 2, "decode")
@@ -72,7 +72,7 @@ def test_prefill_decode_bundles_run(name):
 def test_microbatched_train_step_matches_full_batch():
     cfg = SMOKES["gemma-2b"]
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
     shape = ShapeConfig("t", 32, 8, "train")
     batch = {

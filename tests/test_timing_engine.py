@@ -28,11 +28,20 @@ from repro.core.repartition import (
 )
 from repro.core.allocations import allocation_family
 from repro.core.synth import generate_tasks, workload
-from repro.core.timing import ReplayEngine, TimingEngine
+from repro.core.timing import ReplayEngine, TimingEngine, left_fold
 
 NO_REFINE = SchedulerConfig(refine=False)
 
 SPECS = (A30, A100, TPU_POD_256)
+
+
+def test_left_fold_is_not_compensated_sum():
+    """Chains are folded left to right, as replay adds them; Python's
+    ``sum()`` compensates float rounding and gives another last bit."""
+    values = [0.1] * 10
+    assert left_fold(0.0, values) == 0.9999999999999999
+    assert sum(values) == 1.0
+    assert left_fold(5.0, []) == 5.0
 
 
 def _assert_engines_agree(eng: TimingEngine, ref: ReplayEngine):
