@@ -35,34 +35,16 @@ import pathlib
 import sys
 import time
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
+ROOT = pathlib.Path(__file__).resolve().parent
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
+
+from bench.lib.counters import Counters  # noqa: E402
 
 BATCH_N = 2000          # offline batch size (tasks)
 STREAM_N = 10_000       # served stream length (tasks)
 ARCH = "gemma-2b"
-
-
-class Counters:
-    """Compile seconds and persistent-cache traffic, from jax.monitoring."""
-
-    def __init__(self, jax):
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
 
 
 def check(cond: bool, msg: str) -> None:
@@ -77,13 +59,13 @@ def _timed(counters, fn):
     """(result or the DeviceMismatchError raised, wall s, compile s)."""
     from repro.core.family_eval import DeviceMismatchError
 
-    c0 = counters.compile_s
+    c0 = counters.now.compile_s
     t0 = time.perf_counter()
     try:
         out = fn()
     except DeviceMismatchError as e:
         out = e
-    return out, time.perf_counter() - t0, counters.compile_s - c0
+    return out, time.perf_counter() - t0, counters.now.compile_s - c0
 
 
 def _serve_stream(evaluator: str, seed: int):
@@ -333,9 +315,10 @@ def main() -> int:
     else:
         phase_plan(counters, args.seed)
         phase_live(args.seed)
+    done = counters.now
     print(f"done in {time.perf_counter() - t0:.1f} s; compile "
-          f"{counters.compile_s:.1f} s; persistent cache {counters.hits} "
-          f"hits, {counters.misses} misses")
+          f"{done.compile_s:.1f} s; persistent cache {done.hits} "
+          f"hits, {done.misses} misses")
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": devs[0].device_kind,
         "count": len(devs),

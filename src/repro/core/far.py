@@ -41,6 +41,7 @@ from repro.core.policy import (
 from repro.core.problem import Schedule, Task, area_lower_bound, bind_tasks
 from repro.core.refine import RefineStats, refine_assignment
 from repro.core.repartition import Assignment, replay
+from repro.core.spans import span
 
 
 @dataclasses.dataclass
@@ -126,67 +127,73 @@ def far_schedule(
     :mod:`repro.core.family_eval`."""
     eps = config.eps
     t0 = time.perf_counter()
-    if not tasks:
-        empty = Assignment(spec, {}, {})
-        return FARResult(
-            replay(empty), empty, (), 1, 0, 0, None, 0.0,
-            time.perf_counter() - t0,
-        )
-    # heterogeneous profiles are lowered onto this device's kind here;
-    # size-keyed tasks pass through untouched (the back-compat shim)
-    tasks = bind_tasks(tasks, spec)
-    sizes_needed = set(spec.sizes)
-    for task in tasks:
-        if not sizes_needed <= task.times.keys():
-            missing = [s for s in spec.sizes if s not in task.times]
-            raise ValueError(
-                f"task {task.id} lacks times for sizes {missing} on {spec.name}"
+    with span("repro.plan.family"):
+        if not tasks:
+            empty = Assignment(spec, {}, {})
+            return FARResult(
+                replay(empty), empty, (), 1, 0, 0, None, 0.0,
+                time.perf_counter() - t0,
             )
+        # heterogeneous profiles are lowered onto this device's kind here;
+        # size-keyed tasks pass through untouched (the back-compat shim)
+        tasks = bind_tasks(tasks, spec)
+        sizes_needed = set(spec.sizes)
+        for task in tasks:
+            if not sizes_needed <= task.times.keys():
+                missing = [s for s in spec.sizes if s not in task.times]
+                raise ValueError(
+                    f"task {task.id} lacks times for sizes {missing} "
+                    f"on {spec.name}"
+                )
 
-    first, deltas = allocation_family_deltas(tasks, spec)
-    family_size = len(deltas) + 1
+        first, deltas = allocation_family_deltas(tasks, spec)
+        family_size = len(deltas) + 1
     t1 = time.perf_counter()
 
-    # Phase 2: score the family through the configured evaluator
-    # (family_eval.py).  "sequential" warm-starts per-size LPT groups
-    # across the one-task deltas and scores each candidate with the lean
-    # chains_makespan; "vectorized" lowers the same simulation into a
-    # chunked array program; both select the identical EPS-ordered winner
-    # and only the winner is ever replayed into a Schedule.
-    evaluator = get_evaluator(
-        resolve_evaluator(config, len(tasks), family_size)
-    )
-    winner = evaluator.evaluate(tasks, spec, first, deltas, config)
-    makespan_p2 = winner.makespan
-    win_idx = winner.index
-    assignment = winner.assignment
-    winner_alloc = winner.allocation
-    evaluated = winner.evaluated
+    with span("repro.plan.evaluate"):
+        # Phase 2: score the family through the configured evaluator
+        # (family_eval.py).  "sequential" warm-starts per-size LPT groups
+        # across the one-task deltas and scores each candidate with the
+        # lean chains_makespan; "vectorized" lowers the same simulation
+        # into a chunked array program; both select the identical
+        # EPS-ordered winner and only the winner is ever replayed into a
+        # Schedule.
+        evaluator = get_evaluator(
+            resolve_evaluator(config, len(tasks), family_size)
+        )
+        winner = evaluator.evaluate(tasks, spec, first, deltas, config)
+        makespan_p2 = winner.makespan
+        win_idx = winner.index
+        assignment = winner.assignment
+        winner_alloc = winner.allocation
+        evaluated = winner.evaluated
     t2 = time.perf_counter()
 
-    stats: RefineStats | None = None
-    schedule: Schedule
-    if config.refine:
-        # the winner's un-refined Schedule is never consumed when phase 3
-        # runs (it re-derives the final one), so skip that replay entirely
-        assignment, schedule, stats = refine_assignment(
-            assignment, max_iterations=config.max_refine_iterations,
-            use_engine=config.use_engine,
-        )
-    else:
-        schedule = replay(assignment)
-    if config.deep_refine:
-        from repro.core.multibatch import Tail, seam_refine
+    with span("repro.plan.refine"):
+        stats: RefineStats | None = None
+        schedule: Schedule
+        if config.refine:
+            # the winner's un-refined Schedule is never consumed when
+            # phase 3 runs (it re-derives the final one), so skip that
+            # replay entirely
+            assignment, schedule, stats = refine_assignment(
+                assignment, max_iterations=config.max_refine_iterations,
+                use_engine=config.use_engine,
+            )
+        else:
+            schedule = replay(assignment)
+        if config.deep_refine:
+            from repro.core.multibatch import Tail, seam_refine
 
-        assignment2, schedule2, mv, sw = seam_refine(
-            assignment, Tail.empty(spec), "forward",
-            use_engine=config.use_engine,
-        )
-        if schedule2.makespan < schedule.makespan - eps:
-            assignment, schedule = assignment2, schedule2
-            if stats is not None:
-                stats.moves += mv
-                stats.swaps += sw
+            assignment2, schedule2, mv, sw = seam_refine(
+                assignment, Tail.empty(spec), "forward",
+                use_engine=config.use_engine,
+            )
+            if schedule2.makespan < schedule.makespan - eps:
+                assignment, schedule = assignment2, schedule2
+                if stats is not None:
+                    stats.moves += mv
+                    stats.swaps += sw
     t3 = time.perf_counter()
 
     return FARResult(

@@ -30,6 +30,7 @@ from typing import Callable, Protocol, Sequence, runtime_checkable
 from repro.core.device_spec import DeviceSpec
 from repro.core.problem import EPS, Schedule, Task, bind_tasks, validate_schedule
 from repro.core.repartition import Assignment
+from repro.core.spans import span
 
 
 #: valid SchedulerConfig.evaluator values (the family-evaluator registry
@@ -255,29 +256,30 @@ class BasePolicy:
         tail: object | None = None,
     ) -> PlanResult:
         cfg = config or SchedulerConfig()
-        t0 = time.perf_counter()
-        # instance-type-keyed profiles are lowered onto this device's kind
-        # at the policy boundary (identity for size-keyed tasks)
-        tasks = bind_tasks(tasks, spec)
-        res = self._plan_fresh(tasks, spec, cfg)
-        res.policy = self.name
-        if tail is not None:
-            if res.assignment is None:
-                raise ValueError(
-                    f"policy {self.name!r} produced no assignment; "
-                    "tail-aware planning is unsupported"
-                )
-            from repro.core.multibatch import concatenate
+        with span("repro.plan", policy=self.name, tasks=len(tasks)):
+            t0 = time.perf_counter()
+            # instance-type-keyed profiles are lowered onto this device's
+            # kind at the policy boundary (identity for size-keyed tasks)
+            tasks = bind_tasks(tasks, spec)
+            res = self._plan_fresh(tasks, spec, cfg)
+            res.policy = self.name
+            if tail is not None:
+                if res.assignment is None:
+                    raise ValueError(
+                        f"policy {self.name!r} produced no assignment; "
+                        "tail-aware planning is unsupported"
+                    )
+                from repro.core.multibatch import concatenate
 
-            out = concatenate(
-                res.assignment, tail, mode=cfg.concat_mode,
-                reverse=cfg.reverse, use_engine=cfg.use_engine,
-            )
-            res.schedule = out.schedule
-            res.makespan = out.schedule.makespan
-            res.tail = out.tail
-            res.extras["concat"] = out
-        res.elapsed_s = time.perf_counter() - t0
+                out = concatenate(
+                    res.assignment, tail, mode=cfg.concat_mode,
+                    reverse=cfg.reverse, use_engine=cfg.use_engine,
+                )
+                res.schedule = out.schedule
+                res.makespan = out.schedule.makespan
+                res.tail = out.tail
+                res.extras["concat"] = out
+            res.elapsed_s = time.perf_counter() - t0
         return res
 
     def _plan_fresh(

@@ -24,6 +24,7 @@ import jax
 
 from repro.core.device_spec import DeviceSpec, InstanceNode
 from repro.core.repartition import Assignment
+from repro.core.spans import chip_ids, span
 from repro.launch.mesh import make_submesh
 
 
@@ -82,11 +83,15 @@ def run_live(
         try:
             if tids:
                 devs = devices_of(node)
-                n = len(devs)
-                mesh = make_submesh(devs, data=n, model=1)
+                chips = chip_ids(devs)
+                with span("repro.instance.create", node=repr(node),
+                          chips=chips):
+                    mesh = make_submesh(devs, data=len(devs), model=1)
                 for tid in tids:
                     t0 = time.perf_counter() - init_time
-                    payload = task_fn(tid, mesh)
+                    with span("repro.task", task=tid, node=repr(node),
+                              chips=chips):
+                        payload = task_fn(tid, mesh)
                     t1 = time.perf_counter() - init_time
                     with lock:
                         records.append(LiveRecord(
