@@ -3,16 +3,28 @@
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --smoke \
       --batch 4 --prompt-len 64 --gen 32
 
-Each call builds its model and lowers and compiles its three programs
-(weight init, prefill, decode) ahead of time, then runs them.  Every stage
-is a ``repro.serve.*`` span (``repro.core.spans``) carrying the mesh's
-chips, so a profiler trace names what the host did while the chips idled.
+A call serves one batch with three programs compiled ahead of time:
+weight init, prefill and decode.  The programs are kept for the life of
+the process, keyed on what they depend on: init on the model's
+configuration and the mesh (its devices in order, shape, axis names and
+axis types), prefill and decode on ``(batch, prompt_len)`` too.  A call
+builds, lowers and compiles (or loads from the persistent cache) only the
+programs not kept yet; every call still makes its weights, prefills and
+decodes on the chips.  Every stage is a ``repro.serve.*`` span
+(``repro.core.spans``) carrying the mesh's chips, and the ``repro.serve``
+span names the kept programs it used (``cached="init prefill decode"``;
+empty when it built all three, which reads back as no stat), so a
+profiler trace names what the host did while the chips idled.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
+import threading
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +38,77 @@ from repro.models.config import ShapeConfig
 from repro.models.model import build_model
 from repro.parallel.sharding import make_rules
 from repro.parallel.steps import make_decode_step, make_prefill_step
+
+
+@dataclasses.dataclass
+class _Slot:
+    lock: threading.Lock = dataclasses.field(default_factory=threading.Lock)
+    program: Any = None
+
+
+class _Programs:
+    """Compiled programs by key, each built once per process.  A build
+    holds only its own key's lock: builds for other meshes or shapes go on
+    beside it, and a second caller of the same key waits for the first
+    one's program."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._slots: dict = {}
+
+    def get(self, key, build):
+        """``(program, kept)``: the kept program for ``key``, else the
+        one ``build()`` returns, kept from then on."""
+        with self._lock:
+            slot = self._slots.setdefault(key, _Slot())
+        with slot.lock:
+            if slot.program is not None:
+                return slot.program, True
+            slot.program = build()
+            return slot.program, False
+
+    def clear(self) -> None:
+        with self._lock:
+            self._slots.clear()
+
+
+_PROGRAMS = _Programs()
+
+
+def clear_programs() -> None:
+    """Forget every kept program: the next call builds all it needs."""
+    _PROGRAMS.clear()
+
+
+def _mesh_key(mesh) -> tuple:
+    """What a program depends on of ``mesh``: a new ``Mesh`` over the same
+    devices, in the same layout, has the same key."""
+    return (tuple(d.id for d in mesh.devices.flat), mesh.devices.shape,
+            mesh.axis_names, mesh.axis_types)
+
+
+def _jitted(cfg, mesh, batch: int, prompt_len: int, chips: str) -> dict:
+    """The model's three programs for one shape on ``mesh``, jitted with
+    their shardings, by name."""
+    with span("repro.serve.build", chips=chips):
+        model = build_model(cfg)
+        rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
+        pre = make_prefill_step(
+            model, rules, mesh,
+            ShapeConfig("serve", prompt_len, batch, "prefill"))
+        dec = make_decode_step(
+            model, rules, mesh,
+            ShapeConfig("serve", prompt_len, batch, "decode"))
+        return {
+            # weights are made in place on the mesh: an eager init would
+            # put every job's full copy on the first device first
+            "init": jax.jit(model.init, out_shardings=pre.in_shardings[0]),
+            "prefill": jax.jit(pre.fn, in_shardings=pre.in_shardings,
+                               out_shardings=pre.out_shardings),
+            "decode": jax.jit(dec.fn, in_shardings=dec.in_shardings,
+                              out_shardings=dec.out_shardings,
+                              donate_argnums=dec.donate_argnums),
+        }
 
 
 def _compile(program: str, fn, chips: str, *args):
@@ -52,43 +135,39 @@ def serve(
         n = len(jax.devices())
         mesh = make_mesh((n, 1), ("data", "model"))
     chips = chip_ids(mesh.devices.flat)
+    cfg = get_smoke(arch) if smoke else get(arch)
+    on_mesh = (cfg, _mesh_key(mesh))
     with span("repro.serve", chips=chips, batch=batch, prompt=prompt_len,
-              gen=gen), mesh:
-        with span("repro.serve.build", chips=chips):
-            cfg = get_smoke(arch) if smoke else get(arch)
-            model = build_model(cfg)
-            rules = make_rules(cfg, mesh_shape_dict(mesh), fsdp=False)
-            pre = make_prefill_step(
-                model, rules, mesh,
-                ShapeConfig("serve", prompt_len, batch, "prefill"))
-            dec = make_decode_step(
-                model, rules, mesh,
-                ShapeConfig("serve", prompt_len, batch, "decode"))
-            rng = np.random.default_rng(seed)
-            prompts = rng.integers(
-                0, cfg.vocab_size, size=(batch, prompt_len)
-            ).astype(np.int32)
-            batch_in = {"tokens": jnp.asarray(prompts)}
-            if cfg.is_encoder_decoder:
-                batch_in["frames"] = jnp.zeros(
-                    (batch, cfg.encoder_frames, cfg.d_model), jnp.bfloat16
-                )
-            init_key = jax.random.key(0)
-            # weights are made in place on the mesh: an eager init would
-            # put every job's full copy on the first device first
-            init_fn = jax.jit(model.init, out_shardings=pre.in_shardings[0])
-            prefill_fn = jax.jit(pre.fn, in_shardings=pre.in_shardings,
-                                 out_shardings=pre.out_shardings)
-            decode_fn = jax.jit(dec.fn, in_shardings=dec.in_shardings,
-                                out_shardings=dec.out_shardings,
-                                donate_argnums=dec.donate_argnums)
+              gen=gen) as job, mesh:
+        rng = np.random.default_rng(seed)
+        prompts = rng.integers(
+            0, cfg.vocab_size, size=(batch, prompt_len)
+        ).astype(np.int32)
+        batch_in = {"tokens": jnp.asarray(prompts)}
+        if cfg.is_encoder_decoder:
+            batch_in["frames"] = jnp.zeros(
+                (batch, cfg.encoder_frames, cfg.d_model), jnp.bfloat16
+            )
+        init_key = jax.random.key(0)
+        jitted = functools.cache(
+            functools.partial(_jitted, cfg, mesh, batch, prompt_len, chips))
+        kept = []
 
-        init = _compile("init", init_fn, chips, init_key)
-        prefill = _compile("prefill", prefill_fn, chips, init.out_info,
-                           batch_in)
-        decode = _compile("decode", decode_fn, chips, init.out_info,
-                          prefill.out_info[1],
-                          jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+        def program(name: str, key: tuple, *args):
+            out, hit = _PROGRAMS.get(
+                (name, *key),
+                lambda: _compile(name, jitted()[name], chips, *args))
+            if hit:
+                kept.append(name)
+            return out
+
+        init = program("init", on_mesh, init_key)
+        prefill = program("prefill", (*on_mesh, batch, prompt_len),
+                          init.out_info, batch_in)
+        decode = program("decode", (*on_mesh, batch, prompt_len),
+                         init.out_info, prefill.out_info[1],
+                         jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+        job.set_metadata(cached=" ".join(kept))
 
         with span("repro.serve.init", chips=chips):
             params = jax.block_until_ready(init(init_key))

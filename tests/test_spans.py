@@ -46,13 +46,14 @@ def _parent(spans, child):
 @pytest.fixture(scope="module")
 def traced_job(tmp_path_factory):
     """One FAR plan of two jobs on ``V5E_1`` and one of them served live,
-    under the profiler with its Python tracer off."""
+    under the profiler with its Python tracer off, from an empty store of
+    compiled programs: the job builds all three."""
     import jax
 
     from repro.core.device_spec import V5E_1
     from repro.core.policy import get_policy
     from repro.core.problem import Task
-    from repro.launch.serve import serve
+    from repro.launch.serve import clear_programs, serve
     from repro.runtime.live import run_live
 
     out = {}
@@ -64,6 +65,7 @@ def traced_job(tmp_path_factory):
                               log_fn=lambda *_: None)
         return {}
 
+    clear_programs()
     trace_dir = tmp_path_factory.mktemp("trace")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -101,6 +103,7 @@ def test_served_path_span_tree(traced_job):
     assert _parent(spans, job) is task
     assert job[1]["batch"] == BATCH and job[1]["prompt"] == PROMPT
     assert job[1]["gen"] == GEN
+    assert "cached" not in job[1]  # an empty stat: no program was kept
     inner = [s for s in spans if s[0].startswith("repro.serve.")]
     assert all(_parent(spans, s) is job for s in inner)
     names = sorted(s[0] for s in inner)
