@@ -1,7 +1,7 @@
 """Compile and persistent-cache counters, from ``jax.monitoring``.
 
-Copied from ``chip_smoke.py`` (``Counters``) and extended with the trace
-and lowering durations, so that the benchmark's reading of them cannot
+Kept with the benchmark, and read by ``chip_smoke.py`` too, so that the
+benchmark's reading of jax's trace, lowering and compile durations cannot
 move with the program.  ``backend_compile_duration`` wraps both a real
 compile and a load from the persistent cache; ``misses`` counts the real
 compiles.

@@ -1,13 +1,23 @@
 """Reduction of a profiler trace to the benchmark's device numbers.
 
-``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote and keeps
-a small neutral form of it: the window (the host span ``bench.window``),
-each chip's device operations (the ``XLA Ops`` line of each
-``/device:TPU:<n>`` plane) and the benchmark's own host spans (names that
-start with ``bench.``).  Everything else reads that form, so it can be
-checked on a recorded fixture without a chip.
+``extract`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote, in one
+pass over its planes, and keeps a small neutral form of it:
 
-Host spans and device operations share the profiler's clock.
+* ``window_ns``: the host span ``bench.window``;
+* ``devices``: each chip's device operations, the ``XLA Ops`` line of its
+  ``/device:TPU:<n>`` plane, as ``[name, start_ns, duration_ns]``;
+* ``modules``: each chip's program executions, the ``XLA Modules`` line of
+  the same plane, one event per run of a jitted program, named by it
+  (``jit_prefill_step(<fingerprint>)``): the operations that run inside
+  one belong to that program;
+* ``spans``: the host spans of the benchmark (``bench.``) and of the
+  program (``repro.``), each stat rendered into the name as
+  `` key=value`` with a value's spaces written as commas, so
+  ``chips="0 1"`` reads ``chips=0,1``.
+
+Everything else reads that form, so it can be checked on a recorded
+fixture without a chip.  Host spans and device events share the
+profiler's clock.
 """
 
 from __future__ import annotations
@@ -20,9 +30,20 @@ from bench.lib.window import union_length
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
-SPAN_PREFIX = "bench."
+MODULES_LINE = "XLA Modules"
+SPAN_PREFIXES = ("bench.", "repro.")
 WINDOW_SPAN = "bench.window"
 CHIPS = re.compile(r"chips=([\d,]+)")
+
+
+def neutral_name(name: str, stats) -> str:
+    """``name`` followed by `` key=value`` for each stat."""
+    return name + "".join(
+        f" {k}={str(v).replace(' ', ',')}" for k, v in stats)
+
+
+def _events(line):
+    return [[e.name, e.start_ns, e.duration_ns] for e in line.events]
 
 
 def extract(trace_dir: str) -> dict:
@@ -32,28 +53,28 @@ def extract(trace_dir: str) -> dict:
     paths = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    data = ProfileData.from_file(paths[-1])
-    devices, spans = {}, []
-    for plane in data.planes:
+    devices, modules, spans = {}, {}, []
+    for plane in ProfileData.from_file(paths[-1]).planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
-            devices[int(m.group(1))] = [
-                [e.name, e.start_ns, e.duration_ns]
-                for line in plane.lines if line.name == OPS_LINE
-                for e in line.events
-            ]
+            chip = int(m.group(1))
+            devices[chip], modules[chip] = [], []
+            for line in plane.lines:
+                if line.name == OPS_LINE:
+                    devices[chip] = _events(line)
+                elif line.name == MODULES_LINE:
+                    modules[chip] = _events(line)
         elif plane.name.startswith("/host:"):
             spans.extend(
-                [e.name, e.start_ns, e.duration_ns]
+                [neutral_name(e.name, e.stats), e.start_ns, e.duration_ns]
                 for line in plane.lines for e in line.events
-                if e.name.startswith(SPAN_PREFIX)
-            )
+                if e.name.startswith(SPAN_PREFIXES))
     windows = [s for s in spans if s[0] == WINDOW_SPAN]
     if len(windows) != 1:
         raise ValueError(f"want one {WINDOW_SPAN} span, found {len(windows)}")
     _, start, dur = windows[0]
     return {"window_ns": [start, start + dur], "devices": devices,
-            "spans": spans}
+            "modules": modules, "spans": spans}
 
 
 def _intervals(ops):
@@ -115,8 +136,9 @@ def _gaps(ops, lo, hi):
 
 def _label(spans, chip: int, t: int) -> str:
     """What the host was doing for ``chip`` at time ``t``: the innermost
-    benchmark span that covers ``t`` and is about this chip (a span that
-    names chips) or about the whole host (one that names none)."""
+    host span, the benchmark's or the program's, that covers ``t`` and is
+    about this chip (a span that names chips) or about the whole host (one
+    that names none)."""
     best = None
     for name, s, d in spans:
         if not s <= t < s + d or name == WINDOW_SPAN:
@@ -126,7 +148,7 @@ def _label(spans, chip: int, t: int) -> str:
             continue
         if best is None or d < best[1]:
             best = (name, d)
-    return best[0] if best else "no benchmark span"
+    return best[0] if best else "no host span"
 
 
 def idle_gaps(form: dict, chips, k: int = 10) -> list:

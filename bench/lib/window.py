@@ -19,11 +19,12 @@ def rate(work: float, seconds: float) -> float:
 
 def union_length(intervals: Iterable[tuple[float, float]],
                  lo: float, hi: float) -> float:
-    """Length of the union of ``intervals``, clipped to ``[lo, hi]``."""
+    """Length of the union of ``intervals``, clipped to ``[lo, hi]``: an
+    interval that lies wholly outside it adds nothing."""
     clipped = sorted((max(a, lo), min(b, hi)) for a, b in intervals)
     total, end = 0.0, lo
     for a, b in clipped:
-        if b <= end:
+        if b <= max(a, end):
             continue
         total += b - max(a, end)
         end = b

@@ -119,7 +119,7 @@ def main(argv=None) -> int:
     rec = runner.run(config, traffic, devices, args.seed, args.seconds,
                      tracer)
     rec.update(setup_s=rec["setup_end"] - T0, window_s=rec["hi"] - rec["lo"],
-               model=config["model"],
+               model=config["model"], reference=config["reference"],
                peak=device.peaks(devices[0].device_kind),
                trace=getattr(tracer, "form", None))
 

@@ -1,6 +1,8 @@
 """A run of the live cell on the CPU at a small size, with the chip check
 skipped, and with the timed path broken underneath: each fault has to
-turn ``correct`` false."""
+turn ``correct`` false.  The window closes after its first batch.  Each
+test runs on both deployments: one chip, and the four-chip host on four
+CPU devices, planned by FAR over ``V5E_2X2`` and run on its sub-meshes."""
 
 import contextlib
 
@@ -10,11 +12,17 @@ import pytest
 from bench.run import judge
 from bench.runners import live
 
+pytestmark = pytest.mark.parametrize(
+    "small_live", ["v5e1-qwen2.5-3b", "v5e2x2-qwen2.5-3b"], indirect=True)
+
 
 def _run(config, traffic, seed=11):
     import jax
 
-    return live.run(config, traffic, jax.devices()[:1], seed, 0.5,
+    from repro.core.device_spec import SPECS
+
+    chips = SPECS[config["pool"]["spec"]].n_slices
+    return live.run(config, traffic, jax.devices()[:chips], seed, 1e-3,
                     contextlib.nullcontext())
 
 
@@ -41,10 +49,13 @@ def _patch_serve(monkeypatch, change):
 def test_sound_run_passes_its_structural_checks(small_live):
     config, traffic = small_live
     rec = _run(config, traffic)
-    assert rec["attempted"] == 4 and rec["failed"] == 0
+    assert rec["attempted"] == config["pool"]["batch_jobs"]
+    assert rec["failed"] == 0
     assert [n for n in _wrong(rec) if n != "mean_logit_gap"] == []
     assert np.isfinite(rec["checks"]["mean_logit_gap"][0])
     assert rec["hi"] > rec["lo"] >= rec["setup_end"]
+    # every chip of the deployment ran a job
+    assert {c for j in rec["jobs"] for c in j["chips"]} == set(rec["chips"])
 
 
 def test_an_answer_altered_where_it_is_produced_fails(small_live,

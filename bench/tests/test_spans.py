@@ -1,21 +1,25 @@
 """The program's spans in the neutral form: their names, the idle gaps
 they label, and the per-job split of the served path, on a hand-made
 form whose answers are known, on a slice of a trace recorded on a v5e
-chip, and on a trace recorded on the CPU."""
+chip, and on traces recorded on the CPU."""
 
+import gzip
 import json
+import re
 
 import pytest
 
 from bench.lib import spans, trace
+from bench.lib.window import union_length
+from bench.run import _reader
 from conftest import ROOT
 
-FIXTURE = ROOT / "bench" / "tests" / "fixtures" / "trace_v5e1_spans.json"
+FIXTURE = ROOT / "bench" / "tests" / "fixtures" / "trace_v5e1_spans.json.gz"
 MS = 1_000_000  # ns
 
 
 def _span(name, start, dur, **stats):
-    return [spans.neutral_name(name, stats.items()), start * MS, dur * MS]
+    return [trace.neutral_name(name, stats.items()), start * MS, dur * MS]
 
 
 def _form():
@@ -60,10 +64,10 @@ def _form():
 
 
 def test_stats_render_into_the_name():
-    assert spans.neutral_name(
+    assert trace.neutral_name(
         "repro.serve", [("chips", "0 1"), ("batch", 8)]
     ) == "repro.serve chips=0,1 batch=8"
-    assert spans.neutral_name("repro.serve.build", [("chips", 3)]) == (
+    assert trace.neutral_name("repro.serve.build", [("chips", 3)]) == (
         "repro.serve.build chips=3")
     assert spans.chips_of("repro.serve chips=0,1 batch=8") == (0, 1)
     assert spans.chips_of("repro.plan policy=far tasks=2") is None
@@ -101,78 +105,92 @@ def test_split_of_the_served_path():
     assert spans.decode_step_ms(form) == pytest.approx(4.0)
 
 
-def test_idle_and_busy_under_program_spans():
-    form = _form()
-    # chip 0 idles 89 ms; the lower and compile spans cover 10-30 ms of it
-    assert spans.idle_share_under(
-        form, [0], {"repro.serve.lower", "repro.serve.compile"}
-    ) == pytest.approx(20 / 89)
-    # chip 0 is busy 11 ms, 9 of them inside its job; chip 1's 8 all inside
-    assert spans.busy_share_under(form, [0], "repro.serve") == (
-        pytest.approx(9 / 11))
-    assert spans.busy_share_under(form, [0, 1], "repro.serve") == (
-        pytest.approx(17 / 19))
-
-
 def test_a_window_without_jobs_reads_nothing():
     form = {"window_ns": [0, MS], "devices": {}, "spans": []}
     assert spans.jobs(form) == [] and spans.per_job(form, "build") is None
     assert spans.mean_ms(form, "repro.plan") is None
     assert spans.decode_step_ms(form) is None
-    assert spans.idle_share_under(form, [0], {"repro.serve.lower"}) == 0.0
 
 
-def test_recorded_chip_trace_attributes_its_idle_gap():
-    """11.9 s of a traced window on one v5e chip: the end of one job's
-    decode, then the next job's stages up to its prefill, with the
-    program's spans added to the neutral form."""
-    form = json.loads(FIXTURE.read_text())
-    form["devices"] = {int(k): v for k, v in form["devices"].items()}
-    (long_gap,) = [g for g in trace.idle_gaps(form, [0]) if g[1] > 1.0]
-    assert long_gap[0] == "chip 0: repro.serve.compile chips=0 program=init"
-    assert spans.busy_share_under(form, [0], "repro.serve") == 1.0
-    assert spans.idle_share_under(
-        form, [0], {"repro.serve.lower", "repro.serve.compile"}) > 0.99
-    assert spans.mean_ms(form, "repro.serve.init") == pytest.approx(
-        118.387252)
+def _recorded():
+    with gzip.open(FIXTURE, "rt") as f:
+        form = json.load(f)
+    for key in ("devices", "modules"):
+        form[key] = {int(k): v for k, v in form[key].items()}
+    return form
 
 
-def test_small_cell_under_the_tracer_reads_every_stage(small_live,
-                                                     monkeypatch):
-    """The live cell on the CPU under the benchmark's ``Tracer``, with the
-    program's spans added to the neutral form: every span metric finds
-    its spans (CPU seconds, not device numbers)."""
+def test_recorded_chip_trace_keeps_program_spans_and_modules():
+    """1.27 s of a traced window of the one-chip cell on a v5e chip: a
+    batch's instance formation and its first job, 16x1024 with 32 tokens
+    out, as ``extract`` kept it."""
+    form = _recorded()
+    lo, hi = form["window_ns"]
+    assert {spans.base(n) for n, _, _ in form["spans"]} >= {
+        "bench.window", "repro.plan", "repro.instance.create", "repro.task",
+        "repro.serve", "repro.serve.init", "repro.serve.prefill",
+        "repro.serve.decode_step"}
+    programs = [m for m in form["modules"][0]
+                if re.match(r"jit_(init|prefill_step|decode_step)\(", m[0])]
+    assert {m[0].split("(")[0] for m in programs} == {
+        "jit_init", "jit_prefill_step", "jit_decode_step"}
+    # the operations of the three programs: at least 95% of busy time
+    ops = [(max(s, lo), min(s + d, hi)) for _, s, d in form["devices"][0]]
+    inside = [(max(a, s), min(b, s + d)) for a, b in ops
+              for _, s, d in programs if a < s + d and s < b]
+    busy = union_length(ops, lo, hi)
+    assert union_length(inside, lo, hi) >= 0.95 * busy > 0
+    # every long idle gap is named by a program span on that chip
+    assert all(label.startswith("chip 0: repro.")
+               for label, _ in trace.idle_gaps(form, [0]))
+
+
+@pytest.mark.parametrize("metric, value", [
+    ("prefill_ms.live", 674.803594),
+    ("decode_step_ms.live", 15.237449),
+    ("serve_init_s.live", 0.111031228),
+    ("instance_create_ms.live", 0.03483),
+])
+def test_span_readers_on_the_recorded_chip_trace(metric, value):
+    assert _reader(metric)({"trace": _recorded()}) == pytest.approx(value)
+
+
+def test_span_readers_without_a_trace_read_nothing():
+    for metric in ("prefill_ms.live", "decode_step_ms.live",
+                   "serve_init_s.live", "instance_create_ms.live"):
+        assert _reader(metric)({"trace": None}) is None
+
+
+def test_small_cell_under_the_tracer_reads_every_stage(small_live):
+    """The live cell on the CPU under the benchmark's ``Tracer``: the form
+    keeps the program's spans, and every span metric finds them (CPU
+    seconds, not device numbers).  Set-up built every program, so the
+    window's jobs build, lower and compile nothing."""
     import jax
 
     from bench.run import Tracer
     from bench.runners import live
 
-    real = trace.extract
-
-    def extract(trace_dir):
-        form = real(trace_dir)
-        form["spans"] += spans.read(trace_dir)
-        return form
-
-    monkeypatch.setattr(trace, "extract", extract)
     tracer = Tracer()
-    live.run(*small_live, jax.devices()[:1], 11, 0.5, tracer)
+    rec = live.run(*small_live, jax.devices()[:1], 11, 1e-3, tracer)
     form = tracer.form
-    assert len(spans.jobs(form)) == 4
+    assert len(spans.jobs(form)) == rec["attempted"] == 4
     for value in (spans.mean_ms(form, "repro.plan"),
                   spans.mean_ms(form, "repro.instance.create"),
-                  spans.per_job(form, "build"),
-                  spans.per_job(form, "lower", "compile"),
                   spans.per_job(form, "init"),
                   spans.mean_ms(form, "repro.serve.prefill"),
                   spans.decode_step_ms(form)):
         assert value is not None and value > 0
-    parts = sum(spans.per_job(form, stage) for stage in spans.STAGES)
-    whole = sum(j["seconds"] for j in spans.jobs(form)) / 4
-    assert 0.95 * whole < parts <= whole
+    assert spans.per_job(form, "build", "lower", "compile") == 0
+    gens = sorted(j["job"].gen for j in rec["jobs"])
+    assert sorted(len(j["stages"]["decode_step"]) + 1
+                  for j in spans.jobs(form)) == gens
+    for j in spans.jobs(form):
+        assert len(j["stages"]["init"]) == len(j["stages"]["prefill"]) == 1
+        assert sum(map(sum, j["stages"].values())) <= j["seconds"]
 
 
-def test_read_a_recorded_trace(tmp_path):
+def test_extract_keeps_benchmark_and_program_spans(tmp_path):
     import jax
 
     from repro.core.spans import span
@@ -181,14 +199,23 @@ def test_read_a_recorded_trace(tmp_path):
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
-        with span("repro.serve", chips="0 1", batch=2):
-            with span("repro.serve.build", chips="0"):
+        with jax.profiler.TraceAnnotation("bench.window"):
+            with span("repro.serve", chips="0 1", batch=2):
+                with span("repro.serve.build", chips="0"):
+                    pass
+            with jax.profiler.TraceAnnotation("bench.plan"):
                 pass
-        with jax.profiler.TraceAnnotation("bench.plan"):
-            pass
+            with jax.profiler.TraceAnnotation("other.span"):
+                pass
     finally:
         jax.profiler.stop_trace()
-    got = sorted(spans.read(str(tmp_path)), key=lambda s: s[1])
-    assert [s[0] for s in got] == ["repro.serve chips=0,1 batch=2",
-                                   "repro.serve.build chips=0"]
-    assert got[0][1] <= got[1][1] and got[1][2] <= got[0][2]
+    form = trace.extract(str(tmp_path))
+    got = sorted(form["spans"], key=lambda s: s[1])
+    assert [s[0] for s in got] == ["bench.window",
+                                   "repro.serve chips=0,1 batch=2",
+                                   "repro.serve.build chips=0",
+                                   "bench.plan"]
+    assert form["window_ns"] == [got[0][1], got[0][1] + got[0][2]]
+    assert got[1][1] <= got[2][1] and got[2][2] <= got[1][2]
+    # the CPU has no TPU planes: no operations and no modules
+    assert form["devices"] == form["modules"] == {}

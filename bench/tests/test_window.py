@@ -15,6 +15,8 @@ def test_rate_is_all_work_over_the_whole_window():
     ([(1, 3), (4, 6)], 0, 10, 4),
     ([(-5, 2), (8, 20)], 0, 10, 4),        # clipped to the window
     ([(3, 4), (1, 2), (1.5, 3.5)], 0, 10, 3),
+    ([(97, 99)], 5, 64, 0),                # wholly after the window
+    ([(-9, -2), (1, 2)], 0, 10, 1),        # wholly before it
 ])
 def test_union_length(intervals, lo, hi, want):
     assert union_length(intervals, lo, hi) == pytest.approx(want)
